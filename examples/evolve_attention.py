@@ -39,6 +39,7 @@ from repro.core import (AgenticVariationOperator, ContinuousEvolution,
                         scenario_specs, topology_names)
 from repro.core.perfmodel import expert_reference, fa_reference, gqa_suite, mha_suite
 from repro.core.population import Lineage
+from repro.launch.compile_cache import enable_compile_cache
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "results")
 
@@ -210,6 +211,7 @@ def main():
     ap.add_argument("--migrant-k", type=int, default=3,
                     help="k for --migrant-policy top-k")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.eval_backend is None:
         args.eval_backend = ("thread" if args.islands or args.scenario_sweep
                              else "inline")

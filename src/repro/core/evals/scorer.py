@@ -1,29 +1,33 @@
 """The inline evaluation path: correctness + profiling in the calling process.
 
 Correctness is executed for real: the genome is materialized into its Pallas
-kernel and run in ``interpret=True`` mode on CPU against the ``ref.py``
-oracle, on a reduced proxy shape (full 32k shapes are not runnable in the
-interpreter; the kernel's behaviour is shape-generic).  Throughput depends on
-the scorer's *fidelity* rung (the evaluation cascade's ladder):
+kernel and run against the ``ref.py`` oracle on a reduced proxy shape — the
+compiled kernel when the process's platform is ``tpu``, the Pallas
+interpreter on ``cpu``.  Throughput depends on the scorer's *fidelity* rung
+(the evaluation cascade's ladder):
 
 - ``perfmodel`` (rung 0, default): ``perfmodel.estimate`` — see that module's
   docstring for the machine model.  Bit-identical to the pre-cascade scorer.
 - ``hlo`` (rung 1): trace the genome's kernel to HLO on the reduced proxy
   shape and score with the roofline three-term model over
   ``HloAnalysis.summary`` totals (compute/memory/collective).
-- ``measured`` (rung 2): compile-and-time the kernel on the proxy shape when
-  an accelerator is attached; on CPU-only hosts, fall back to the
-  deterministic ``perfmodel.measured_estimate`` modelled timer.
+- ``measured`` (rung 2): on a TPU, compile the genome's kernel at each suite
+  config's own shape and time it on the device; a config the compiler
+  refuses scores 0 with the compiler's message as its reason.  On a CPU-only
+  process, the deterministic ``perfmodel.measured_estimate`` modelled timer.
+  ``ScoreVector.timer`` names which of the two produced the values.
 
-:class:`Scorer` is a deterministic function of the genome: the proxy inputs
-are rebuilt from ``rng_seed`` alone, so two scorers with the same suite,
-seed, and fidelity — in the same process or different ones — return
-bit-identical :class:`ScoreVector`s.  The process backend leans on exactly
-this property.  :meth:`Scorer.score_key` carries the fidelity into the cache
-key (``cache.fidelity_key``) so rungs never alias one another.
+:class:`Scorer` is a deterministic function of the genome at rungs 0 and 1
+and at the modelled rung 2: the proxy inputs are rebuilt from ``rng_seed``
+alone, so two scorers with the same suite, seed, and fidelity — in the same
+process or different ones — return bit-identical :class:`ScoreVector`\\ s.
+The process backend leans on exactly this property.  Device timings vary
+from run to run.  :meth:`Scorer.score_key` carries the fidelity into the
+cache key (``cache.fidelity_key``) so rungs never alias one another.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import math
 import os
@@ -40,10 +44,16 @@ from repro.core.evals.cache import (FIDELITIES, HLO, MEASURED, PERFMODEL,
 from repro.core.evals.vector import ScoreVector
 from repro.core.perfmodel import (KERNEL_LAUNCH, BenchConfig, estimate,
                                   estimate_batch, measured_estimate,
-                                  mha_suite)
+                                  mha_suite, useful_flops)
 from repro.core.search_space import KernelGenome
 
 CORRECTNESS_TOL = 2e-5
+MODELLED_TIMER = "modelled"
+TIMED_REPS = 5
+
+# one chip, one timed kernel at a time: rung-2 evaluations from concurrent
+# islands queue here instead of overlapping on the device
+_DEVICE_LOCK = threading.Lock()
 
 # ---------------------------------------------------------------------------
 # batch-path switch
@@ -278,8 +288,9 @@ class Scorer:
                 tuple(sorted(kw.items())))
 
     def check(self, genome: KernelGenome) -> tuple[bool, str]:
-        """Execute the genome's kernel (interpret mode) against the oracle —
-        memoized per kernel structure in the process-wide bounded LRU."""
+        """Execute the genome's kernel against the oracle (compiled on a
+        TPU, interpreted on CPU) — memoized per kernel structure in the
+        process-wide bounded LRU."""
         key = self.structural_key(genome)
         cached = _CHECK_MEMO.get(key)
         if cached is not None:
@@ -289,17 +300,21 @@ class Scorer:
         return result
 
     def _check_uncached(self, genome: KernelGenome) -> tuple[bool, str]:
+        import jax
         import jax.numpy as jnp
         from repro.kernels.flash_attention import flash_attention
         from repro.kernels.ref import mha_reference
         kw = self._clamped_kwargs(genome)
+        interpret = jax.default_backend() == "cpu"
         for sh, q, k, v in self._proxy_data():
             try:
                 o = flash_attention(q, k, v, causal=sh["causal"], window=sh["window"],
-                                    interpret=True, **kw)
+                                    interpret=interpret, **kw)
             except Exception as e:  # trace/lowering failure
                 return False, f"kernel raised: {type(e).__name__}: {e}"
-            r = mha_reference(q, k, v, causal=sh["causal"], window=sh["window"])
+            # full f32 matmuls: a TPU's default precision rounds f32 to bf16
+            with jax.default_matmul_precision("highest"):
+                r = mha_reference(q, k, v, causal=sh["causal"], window=sh["window"])
             err = float(jnp.max(jnp.abs(o - r)))
             if not math.isfinite(err) or err > CORRECTNESS_TOL:
                 return False, (f"numerical mismatch vs oracle: max|err|={err:.2e} "
@@ -351,17 +366,18 @@ class Scorer:
                                    tuple(0.0 for _ in self.suite), False,
                                    why)
 
+        timer = ""
         if self.fidelity == HLO:
             values, profiles = self._hlo_values(genome)
         elif self.fidelity == MEASURED:
-            values, profiles = self._measured_values(genome)
+            values, profiles, timer = self._measured_values(genome)
         else:
             values, profiles = [], {}
             for cfg in self.suite:
                 p = estimate(genome, cfg)
                 profiles[cfg.name] = p
                 values.append(p.tflops if p.feasible else 0.0)
-        return self._assemble(values, profiles)
+        return self._assemble(values, profiles, timer)
 
     def score_batch(self, genomes: Sequence[KernelGenome]) -> list[ScoreVector]:
         """Batched :meth:`score_uncached`: pay the evaluation cost for every
@@ -403,10 +419,13 @@ class Scorer:
                     out[i] = self._assemble(values, profiles)
             else:                     # hlo/measured stay scalar per genome
                 for i in todo:
-                    values, profiles = (
-                        self._hlo_values(genomes[i]) if self.fidelity == HLO
-                        else self._measured_values(genomes[i]))
-                    out[i] = self._assemble(values, profiles)
+                    timer = ""
+                    if self.fidelity == HLO:
+                        values, profiles = self._hlo_values(genomes[i])
+                    else:
+                        values, profiles, timer = self._measured_values(
+                            genomes[i])
+                    out[i] = self._assemble(values, profiles, timer)
             return out
         finally:
             dur = time.perf_counter() - t0
@@ -415,7 +434,7 @@ class Scorer:
                 obs.span("score", obs.current_trace(), dur_s=dur,
                          rung=self.fidelity, n=len(genomes))
 
-    def _assemble(self, values, profiles) -> ScoreVector:
+    def _assemble(self, values, profiles, timer: str = "") -> ScoreVector:
         """The common ScoreVector assembly of both scoring paths (identical
         failure-string derivation, so batch == scalar bit-for-bit)."""
         failure = ""
@@ -425,7 +444,7 @@ class Scorer:
                 f"{n} ({profiles[n].infeasible_reason})" if n in profiles
                 else n for n in bad)
         return ScoreVector(tuple(c.name for c in self.suite), tuple(values),
-                           True, failure, profiles)
+                           True, failure, profiles, timer)
 
     # -- higher-fidelity rungs -------------------------------------------------
     def _proxy_trace_groups(self):
@@ -447,8 +466,6 @@ class Scorer:
         """Lower the genome's kernel (interpret mode, proxy shape) to HLO and
         return ``HloAnalysis(...).summary()``.  Abstract tracing only — no
         arrays are materialized and nothing executes."""
-        import functools
-
         import jax
         import jax.numpy as jnp
 
@@ -501,63 +518,71 @@ class Scorer:
         return [by_name[c.name] for c in self.suite], profiles
 
     def _measured_values(self, genome: KernelGenome):
-        """Rung 2: compile-and-time on the proxy shape when a real
-        accelerator backs jax; otherwise the deterministic
-        ``perfmodel.measured_estimate`` modelled timer (CPU hosts, CI) so
-        backends stay bit-identical and kill/resume replays."""
+        """Rung 2: device timing when this process runs on a TPU; on a
+        CPU-only process the deterministic ``perfmodel.measured_estimate``
+        modelled timer (CI, the process/service workers), named as such in
+        the returned timer so it is never mistaken for a measurement."""
         import jax
-        if jax.default_backend() != "cpu":      # pragma: no cover - no TPU in CI
+        if jax.default_backend() == "tpu":
             return self._timed_values(genome)
         values, profiles = [], {}
         for cfg in self.suite:
             p = measured_estimate(genome, cfg)
             profiles[cfg.name] = p
             values.append(p.tflops if p.feasible else 0.0)
-        return values, profiles
+        return values, profiles, MODELLED_TIMER
 
-    def _timed_values(self, genome: KernelGenome):  # pragma: no cover - needs TPU
-        """Wall-clock the compiled kernel per proxy mask shape; convert to
-        TFLOP/s via the traced kernel's own HLO flop count."""
-        import functools
-        import time
+    def _timed_values(self, genome: KernelGenome):
+        """Time the compiled kernel on the device at every suite config's
+        own shape: bf16, the genome's own blocks, a warm-up call, then
+        ``TIMED_REPS`` calls each ended by ``block_until_ready`` (median).
+        TFLOP/s is ``perfmodel.useful_flops(cfg)`` over that time — the same
+        work count whatever implements it.  A config the compiler refuses
+        scores 0 with the compiler's message as its infeasible reason.  The
+        profile keeps the model's terms (the calibration's bottleneck
+        classes); its ``tflops`` and ``total_s`` are the measurement."""
+        import dataclasses
 
         import jax
         import jax.numpy as jnp
 
         from repro.kernels.flash_attention import flash_attention
-        from repro.launch.hlo_analysis import HloAnalysis
         kw = genome.kernel_kwargs()
-        kw["block_q"] = max(16, min(kw["block_q"], 2048) // 16)
-        kw["block_k"] = max(16, min(kw["block_k"], 2048) // 16)
-        has_gqa, groups = self._proxy_trace_groups()
-        rng = np.random.default_rng(self.rng_seed)
-        by_name: dict[str, float] = {}
-        profiles: dict = {}
-        for (causal, window), cfgs in sorted(
-                groups.items(), key=lambda kv: (kv[0][0], kv[0][1] is None,
-                                                kv[0][1] or 0)):
-            hq, hkv = 4, (2 if has_gqa else 4)
-            q = jnp.asarray(rng.normal(size=(1, hq, PROXY_SEQ, 64)), jnp.float32)
-            k = jnp.asarray(rng.normal(size=(1, hkv, PROXY_SEQ, 64)), jnp.float32)
-            v = jnp.asarray(rng.normal(size=(1, hkv, PROXY_SEQ, 64)), jnp.float32)
-            try:
-                fn = jax.jit(functools.partial(flash_attention, causal=causal,
-                                               window=window, **kw))
-                compiled = fn.lower(q, k, v).compile()
-                flops = HloAnalysis(compiled.as_text()).summary().get("flops", 0)
-                compiled(q, k, v).block_until_ready()          # warmup
-                t0 = time.perf_counter()
-                for _ in range(3):
-                    compiled(q, k, v).block_until_ready()
-                dt = (time.perf_counter() - t0) / 3
-                value = flops / dt / 1e12 if dt > 0 else 0.0
-            except Exception:
-                value = 0.0
-            for cfg in cfgs:
+        values, profiles = [], {}
+        with _DEVICE_LOCK:
+            for cfg in self.suite:
                 p = estimate(genome, cfg)
-                profiles[cfg.name] = p
-                by_name[cfg.name] = value if p.feasible else 0.0
-        return [by_name[c.name] for c in self.suite], profiles
+                kq, kk, kv = jax.random.split(jax.random.key(self.rng_seed), 3)
+                shape_q = (cfg.batch, cfg.n_heads, cfg.seq_len, cfg.head_dim)
+                shape_kv = (cfg.batch, cfg.n_kv_heads, cfg.seq_len,
+                            cfg.head_dim)
+                q = jax.random.normal(kq, shape_q, jnp.bfloat16)
+                k = jax.random.normal(kk, shape_kv, jnp.bfloat16)
+                v = jax.random.normal(kv, shape_kv, jnp.bfloat16)
+                run = functools.partial(flash_attention, causal=cfg.causal,
+                                        window=cfg.window, **kw)
+                try:
+                    run(q, k, v).block_until_ready()           # compile + warm-up
+                except Exception as e:  # the compiler's refusal is the reason
+                    msg = str(e).strip().splitlines()
+                    profiles[cfg.name] = dataclasses.replace(
+                        p, tflops=0.0, feasible=False,
+                        infeasible_reason=(f"compile: {type(e).__name__}: "
+                                           f"{msg[0][:200] if msg else ''}"))
+                    values.append(0.0)
+                    continue
+                times = []
+                for _ in range(TIMED_REPS):
+                    t0 = time.perf_counter()
+                    run(q, k, v).block_until_ready()
+                    times.append(time.perf_counter() - t0)
+                dt = float(np.median(times))
+                tflops = useful_flops(cfg) / dt / 1e12
+                profiles[cfg.name] = dataclasses.replace(
+                    p, tflops=tflops, total_s=dt, feasible=True,
+                    infeasible_reason="")
+                values.append(tflops)
+        return values, profiles, jax.devices()[0].device_kind
 
     def baselines(self) -> dict:
         """Expert (cuDNN-analogue) and FA-reference scores on this suite."""

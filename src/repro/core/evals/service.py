@@ -989,7 +989,8 @@ class EvalCoordinator:
 
 def _worker_env() -> dict:
     """Child env with this repro checkout importable, whatever the parent's
-    own sys.path tricks were (tests/benchmarks prepend src/ manually)."""
+    own sys.path tricks were (tests/benchmarks prepend src/ manually), and
+    jax held to the CPU."""
     import repro
     # repro may be a namespace package (no __init__): locate it by __path__
     pkg_dir = (os.path.dirname(repro.__file__) if getattr(repro, "__file__",
@@ -1005,6 +1006,9 @@ def _worker_env() -> dict:
     # same switch semantics for observability: a fleet's workers trace
     # exactly when the parent does
     env["REPRO_OBS"] = "1" if obs.enabled() else "0"
+    # workers score rung 0 and the interpreter check only: the chip belongs
+    # to the parent, and a second process that loads the TPU fails or hangs
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

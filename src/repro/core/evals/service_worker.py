@@ -52,8 +52,10 @@ from typing import Optional, Sequence
 
 from repro.core.evals import protocol
 from repro.core.evals.scorer import batch_scoring_enabled
-from repro.core.evals.worker import EvalSpec, _scorer_for, evaluate_genome
+from repro.core.evals.worker import (EvalSpec, _scorer_for, evaluate_genome,
+                                     pin_to_cpu)
 from repro.core.search_space import KernelGenome
+from repro.launch.compile_cache import enable_compile_cache
 
 __all__ = ["EvalServiceWorker", "main"]
 
@@ -365,6 +367,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--name", default=None,
                     help="registry display name (default: worker<N>)")
     args = ap.parse_args(argv)
+    pin_to_cpu()
+    enable_compile_cache()
     host, port = protocol.parse_address(args.connect)
     EvalServiceWorker(host, port, slots=args.slots, name=args.name).run()
     return 0

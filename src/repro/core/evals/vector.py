@@ -23,6 +23,10 @@ class ScoreVector:
     correct: bool
     failure: str = ""
     profiles: dict = field(default_factory=dict)   # name -> Profile
+    # what produced the values of a measured (rung-2) score: the device kind
+    # that timed the kernel, or "modelled" for the CPU stand-in; "" below
+    # rung 2, where no timer runs
+    timer: str = ""
 
     @property
     def geomean(self) -> float:

@@ -19,6 +19,7 @@ the full-payload fallback for executors whose warm set is unknown.
 """
 from __future__ import annotations
 
+import os
 import threading
 from collections import OrderedDict
 from dataclasses import dataclass
@@ -137,10 +138,23 @@ def _scorer_for(spec: EvalSpec) -> Scorer:
     return scorer
 
 
+def pin_to_cpu() -> None:
+    """Keep this worker process off the accelerator.  Workers pay rung 0
+    and the interpreter check only; the chip belongs to the parent, and a
+    second process that loads the TPU fails or hangs.  The environment
+    covers whatever this process starts; the config update covers a jax
+    that a forked worker inherited already imported, which has read the
+    environment before this ran."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def warm_worker(specs: Sequence) -> None:
-    """Process-pool initializer: pre-build the scorer (and its jax proxy
-    inputs) for every suite this pool will serve, so the first real
-    evaluation in each worker pays no import/tracing-warmup latency.
+    """Process-pool initializer: pin the worker to the CPU, then pre-build
+    the scorer (and its jax proxy inputs) for every suite this pool will
+    serve, so the first real evaluation in each worker pays no
+    import/tracing-warmup latency.
     Accepts ``(spec_id, spec)`` pairs (registered for the compact
     :func:`evaluate_frame` path) or bare :class:`EvalSpec`\\ s.
 
@@ -148,6 +162,7 @@ def warm_worker(specs: Sequence) -> None:
     evaluation is a mix of GIL-bound Python tracing (what the process pool
     parallelizes) and XLA ops that parallelize internally — pinning workers
     to one core was measured slower, not faster."""
+    pin_to_cpu()
     for item in specs:
         if isinstance(item, EvalSpec):
             spec = item

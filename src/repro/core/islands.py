@@ -683,12 +683,13 @@ class IslandEvolution:
         # higher-fidelity rungs of each suite (cascade only): correctness was
         # already verified at rung 0, so the expensive rungs skip it
         rung_specs = {
-            key: [EvalSpec(espec.suite, False, espec.rng_seed,
-                           espec.service_latency_s, fid)
-                  for fid in (HLO, MEASURED)]
+            key: {fid: EvalSpec(espec.suite, False, espec.rng_seed,
+                                espec.service_latency_s, fid)
+                  for fid in (HLO, MEASURED)}
             for key, espec in eval_specs.items()} if cascade_eta else {}
+        # rung 2 is paid in this process (below), so workers warm rung 1 only
         warm_specs = tuple(eval_specs.values()) + tuple(
-            s for rungs in rung_specs.values() for s in rungs)
+            rungs[HLO] for rungs in rung_specs.values())
         # which shared resource this backend wants injected is registry
         # metadata, not a name branch — raises the stable 'unknown eval
         # backend' ValueError for unregistered names
@@ -733,13 +734,17 @@ class IslandEvolution:
             self.scorers[key] = sc
             if cascade_eta:
                 # sibling rung backends share the rung-0 cache (fidelity-
-                # prefixed keys keep rungs from aliasing) and the same
-                # executor/coordinator, so the cascade adds no new pools
+                # prefixed keys keep rungs from aliasing).  Rung 1 rides the
+                # island's executor/coordinator; rung 2 times the kernel on
+                # the chip, which only this process may hold, so it runs
+                # inline here whatever the island's backend
                 shared_cache = getattr(sc, "cache", None)
-                rungs = [sc] + [
-                    make_backend(backend, suite=rspec, cache=shared_cache,
-                                 **extra)
-                    for rspec in rung_specs[key]]
+                rungs = [
+                    sc,
+                    make_backend(backend, suite=rung_specs[key][HLO],
+                                 cache=shared_cache, **extra),
+                    make_backend("inline", suite=rung_specs[key][MEASURED],
+                                 cache=shared_cache)]
                 self.cascades[key] = CascadeBackend(
                     rungs, eta=cascade_eta, calibration=self.calibration)
 

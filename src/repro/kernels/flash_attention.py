@@ -41,26 +41,20 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # compiler params moved between JAX versions
-    from jax.experimental.pallas import tpu as pltpu
-
-    def _compiler_params(dimension_semantics):
-        try:
-            return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-        except (AttributeError, TypeError):
-            return pltpu.TPUCompilerParams(dimension_semantics=dimension_semantics)
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
-    def _compiler_params(dimension_semantics):
-        return None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 _STATS_LANES = 128  # TPU vector lane width for the (bq, 128) stats scratch
+# Mosaic's default scoped-VMEM budget on v5e is 16 MiB, which refuses the
+# kv_in_grid=False genomes (all of K/V staged) from s16384 up.  The chip has
+# 128 MiB of VMEM per core; leave some to the compiler's internal scratch.
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+
+def _precision(dtype):
+    """f32 inputs get full-f32 matmuls (a TPU's default rounds them to
+    bf16); narrower inputs keep the compiler's default."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _apply_softcap(s, softcap):
@@ -121,13 +115,16 @@ def _fa_body_grid(
         seq_mod=seq_mod)
     run = ~fully_masked if mask_mode == "block_skip" else jnp.bool_(True)
 
+    prec = _precision(q_ref.dtype)
+
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)            # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)            # (bk, D)
         v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            q, k, (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32,
         ) * scale                                      # (bq, bk)
         s = _apply_softcap(s, softcap)
 
@@ -150,7 +147,8 @@ def _fa_body_grid(
         p = jnp.exp(s - m_new[:, None])                # (bq, bk)
         l_blk = p.sum(axis=-1)
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)
 
         if div_mode == "deferred":
             l_new = l_prev * alpha + l_blk
@@ -198,13 +196,16 @@ def _fa_body_loop(
     """
     i = pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)
+    prec = _precision(q_ref.dtype)
 
     def body(j, carry):
         acc, m_prev, l_prev = carry
-        k = jax.lax.dynamic_slice_in_dim(k_ref[0, 0], j * bk, bk).astype(jnp.float32)
-        v = jax.lax.dynamic_slice_in_dim(v_ref[0, 0], j * bk, bk).astype(jnp.float32)
+        rows = pl.ds(pl.multiple_of(j * bk, bk), bk)
+        k = k_ref[0, 0, rows, :].astype(jnp.float32)
+        v = v_ref[0, 0, rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+            q, k, (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) * scale
         s = _apply_softcap(s, softcap)
         qpos = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         if seq_mod is not None:
@@ -216,7 +217,8 @@ def _fa_body_loop(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])
         pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v, (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)
         acc = (acc.astype(jnp.float32) * alpha[:, None] + pv).astype(adt)
         l_new = l_prev * alpha + p.sum(axis=-1)
         return acc, m_new, l_new
@@ -321,12 +323,14 @@ def flash_attention(
             out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
             out_shape=out_shape,
             scratch_shapes=[
-                _VMEM((bq, D), acc_jdtype),
-                _VMEM((bq, _STATS_LANES), jnp.float32),
-                _VMEM((bq, _STATS_LANES), jnp.float32),
+                pltpu.VMEM((bq, D), acc_jdtype),
+                pltpu.VMEM((bq, _STATS_LANES), jnp.float32),
+                pltpu.VMEM((bq, _STATS_LANES), jnp.float32),
             ],
-            compiler_params=_compiler_params(
-                ("parallel", "parallel", "parallel", "arbitrary")),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(q, k, v)
     else:
@@ -342,7 +346,9 @@ def flash_attention(
             ],
             out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i: (b, h, i, 0)),
             out_shape=out_shape,
-            compiler_params=_compiler_params(("parallel", "parallel", "parallel")),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(q, k, v)
 

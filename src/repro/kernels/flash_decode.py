@@ -6,7 +6,9 @@ processed together as the row dimension of the QK matmul, so the MXU sees a
 analogue of the paper's GQA adaptation (§4.3, 30-minute transfer).
 
 Grid: (B, Hkv, n_kv_blocks); the KV-block dimension is "arbitrary" and
-carries the online-softmax stats in VMEM scratch.
+carries the online-softmax stats in VMEM scratch.  ``valid_len`` is a
+scalar-prefetch operand: it lands in SMEM before the grid runs, and each
+step reads its row's length from there.
 """
 from __future__ import annotations
 
@@ -16,8 +18,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_attention import _VMEM, _compiler_params, NEG_INF, _apply_softcap
+from repro.kernels.flash_attention import NEG_INF, _apply_softcap
 
 
 def _decode_body(
@@ -32,7 +35,7 @@ def _decode_body(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    valid = vl_ref[0]
+    valid = vl_ref[b]
     # skip blocks entirely past the live region
     @pl.when(j * bk < valid)
     def _compute():
@@ -89,24 +92,28 @@ def flash_decode(
     nk = (L + pad) // bk
 
     q4 = q.reshape(B, Hkv, rep, D)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, Hkv, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, rep, D), lambda b, h, j, vl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, j, vl: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, j, vl: (b, h, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, rep, D), lambda b, h, j, vl: (b, h, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((rep, D), jnp.float32),
+            pltpu.VMEM((rep, 128), jnp.float32),
+            pltpu.VMEM((rep, 128), jnp.float32),
+        ],
+    )
     out = pl.pallas_call(
         functools.partial(_decode_body, scale=scale_, softcap=softcap,
                           bk=bk, nk=nk, rep=rep),
-        grid=(B, Hkv, nk),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
-            pl.BlockSpec((1, 1, rep, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, j: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, rep, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
-        scratch_shapes=[
-            _VMEM((rep, D), jnp.float32),
-            _VMEM((rep, 128), jnp.float32),
-            _VMEM((rep, 128), jnp.float32),
-        ],
-        compiler_params=_compiler_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(valid_len.astype(jnp.int32), q4, k_cache, v_cache)
     return out.reshape(B, Hq, D)

@@ -20,7 +20,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from repro.distributed.context import axis_size, batch_axes, div_axis, get_mesh
 from repro.kernels import ref as _ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.flash_decode import flash_decode as _flash_decode
@@ -47,6 +49,32 @@ def resolve_impl(impl: Optional[str] = None) -> str:
     return impl
 
 
+def _per_shard(kernel, *operands, n_heads: int, n_kv_heads: int):
+    """Run a Pallas ``kernel`` on each device's share of its operands.
+
+    The compiler cannot partition a custom call, so under an active mesh
+    the call goes through ``shard_map``: batch rows over the batch axes and
+    heads over the model axis, each where they divide.  Every operand and
+    the output lead with (batch, heads) — the rank-1 ``valid_len`` with
+    batch alone.  Without a mesh the kernel runs as it is."""
+    mesh = get_mesh()
+    if mesh is None:
+        return kernel(*operands)
+    ba = batch_axes()
+    n_batch = 1
+    for a in ba:
+        n_batch *= axis_size(a)
+    rows = ba if ba and operands[0].shape[0] % n_batch == 0 else None
+    heads = "model" if div_axis(n_heads) and div_axis(n_kv_heads) else None
+
+    def spec(x):
+        return P(rows) if x.ndim == 1 else P(rows, heads, *([None] * (x.ndim - 2)))
+
+    return jax.shard_map(kernel, mesh=mesh,
+                         in_specs=tuple(spec(x) for x in operands),
+                         out_specs=spec(operands[0]), check_vma=False)(*operands)
+
+
 def attention(
     q: jnp.ndarray,               # (B, Hq, Sq, D)
     k: jnp.ndarray,               # (B, Hkv, Sk, D)
@@ -64,10 +92,14 @@ def attention(
     g = dict(DEFAULT_ATTN_GENOME, **(genome or {}))
     if impl in ("pallas", "pallas_interpret"):
         assert q_offset == 0, "prefill kernel assumes aligned q/k positions"
-        return _flash(
-            q, k, v, causal=causal, window=window, softcap=softcap, scale=scale,
-            interpret=(impl == "pallas_interpret"), **g,
-        )
+
+        def kernel(q, k, v):
+            return _flash(
+                q, k, v, causal=causal, window=window, softcap=softcap,
+                scale=scale, interpret=(impl == "pallas_interpret"), **g)
+
+        return _per_shard(kernel, q, k, v, n_heads=q.shape[1],
+                          n_kv_heads=k.shape[1])
     if impl == "blocked":
         # causal SWA with a band narrower than the sequence: the q-chunked
         # banded path skips dead key blocks entirely (flops AND bytes)
@@ -102,9 +134,14 @@ def decode_attention(
     impl = resolve_impl(impl)
     g = dict(DEFAULT_ATTN_GENOME, **(genome or {}))
     if impl in ("pallas", "pallas_interpret"):
-        return _flash_decode(
-            q, k_cache, v_cache, valid_len, softcap=softcap, scale=scale,
-            block_k=max(256, g["block_k"]), interpret=(impl == "pallas_interpret"))
+        def kernel(q, k_cache, v_cache, valid_len):
+            return _flash_decode(
+                q, k_cache, v_cache, valid_len, softcap=softcap, scale=scale,
+                block_k=max(256, g["block_k"]),
+                interpret=(impl == "pallas_interpret"))
+
+        return _per_shard(kernel, q, k_cache, v_cache, valid_len,
+                          n_heads=q.shape[1], n_kv_heads=k_cache.shape[1])
     return _ref.decode_reference(
         q, k_cache, v_cache, valid_len, softcap=softcap, scale=scale)
 

@@ -2,10 +2,13 @@
 
 A FUNCTION (not a module-level constant) so importing this module never
 touches jax device state — the dry-run sets XLA_FLAGS before first jax init.
+Axes are Auto: the models place activations with sharding constraints and
+let the compiler propagate the rest (JAX 0.9 otherwise makes them Explicit).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,14 +17,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis is pure data parallelism across ICI-disjoint pods (DCN-linked)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    if hasattr(jax.sharding, "AxisType"):     # jax >= 0.5 explicit-axes API
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
-    """Tiny mesh over whatever devices exist (tests / examples)."""
+    """Mesh over whatever devices exist: (data, model) with ``model``
+    devices on the tensor-parallel axis."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -3,7 +3,6 @@ driver (continuous batching with in-flight slot reuse).
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -12,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.models import decode_step, init_decode_cache, prefill
+from repro.models import decode_step, prefill
 
 
 def make_serve_step(cfg: ArchConfig, compute_dtype=jnp.bfloat16,
@@ -28,10 +27,11 @@ def make_serve_step(cfg: ArchConfig, compute_dtype=jnp.bfloat16,
 
 def make_prefill(cfg: ArchConfig, max_len: int, compute_dtype=jnp.bfloat16,
                  impl: Optional[str] = None, genome: Optional[dict] = None):
+    """Prefill factory; the KV cache is kept in the compute dtype."""
     def prefill_step(params, tokens, **extras):
         return prefill(params, cfg, tokens, max_len,
-                       compute_dtype=compute_dtype, impl=impl, genome=genome,
-                       **extras)
+                       compute_dtype=compute_dtype, cache_dtype=compute_dtype,
+                       impl=impl, genome=genome, **extras)
 
     return prefill_step
 
@@ -63,15 +63,18 @@ class BatchedServer:
         self.max_len = max_len
         self._serve = jax.jit(make_serve_step(cfg, compute_dtype, impl=impl),
                               donate_argnums=(1,))
+        self._prefill = jax.jit(make_prefill(cfg, max_len, compute_dtype,
+                                             impl=impl))
         self._compute_dtype = compute_dtype
-        self._impl = impl
 
     def run(self, requests: list[Request]) -> list[Request]:
         for i in range(0, len(requests), self.batch_size):
             self._run_group(requests[i:i + self.batch_size])
         return requests
 
-    def _run_group(self, group: list[Request]) -> None:
+    def prefill(self, group: list[Request]):
+        """Prefill a group's prompts as one batch, right-aligned to the
+        longest: (last-position logits (B, V), decode cache)."""
         cfg = self.cfg
         B = len(group)
         plen = max(len(r.prompt) for r in group)
@@ -82,10 +85,10 @@ class BatchedServer:
         if cfg.enc_dec:
             extras["enc_frames"] = jnp.zeros((B, plen, cfg.d_model),
                                              self._compute_dtype)
-        logits, cache = prefill(
-            self.params, cfg, jnp.asarray(toks), self.max_len,
-            compute_dtype=self._compute_dtype, cache_dtype=self._compute_dtype,
-            impl=self._impl, **extras)
+        return self._prefill(self.params, jnp.asarray(toks), **extras)
+
+    def _run_group(self, group: list[Request]) -> None:
+        logits, cache = self.prefill(group)
         token = jnp.argmax(logits, -1).astype(jnp.int32)
         steps = max(r.max_new_tokens for r in group)
         for t in range(steps):

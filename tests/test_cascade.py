@@ -11,8 +11,8 @@ import threading
 
 import pytest
 
-from repro.core import (Archipelago, ProcessBackend, ScoreCache, Scorer,
-                        make_backend, seed_genome)
+from repro.core import (Archipelago, InlineBackend, ProcessBackend,
+                        ScoreCache, Scorer, make_backend, seed_genome)
 from repro.core.evals import (FIDELITIES, HLO, MEASURED, PERFMODEL,
                               CascadeBackend, EvalSpec, fidelity_key,
                               intern_spec, key_fidelity)
@@ -122,6 +122,16 @@ def test_measured_rung_is_deterministic_and_term_scaled():
     assert pm.total_s > p0.total_s and pm.tflops < p0.tflops
     assert pm.t_mxu == p0.t_mxu                              # mxu factor 1.0
     assert pm.t_bubble > p0.t_bubble
+
+
+def test_measured_rung_names_its_timer():
+    """On a CPU-only process rung 2 is the modelled timer and says so; the
+    lower rungs run no timer."""
+    g = seed_genome()
+    sv = Scorer(suite=FAST_SUITE, check_correctness=False,
+                fidelity=MEASURED)(g)
+    assert sv.timer == "modelled"
+    assert Scorer(suite=FAST_SUITE, check_correctness=False)(g).timer == ""
 
 
 def test_proxy_window_derives_from_suite_not_constant():
@@ -319,6 +329,22 @@ def test_engine_lineages_bit_identical_with_cascade():
     totals = eng.cascade_totals()
     assert totals["epochs"] > 0
     assert totals["evals"].get(HLO, 0) > 0             # promotion really ran
+
+
+def test_engine_pays_rung2_in_parent_under_process_backend():
+    """Rung 2 times kernels on the chip, which only the parent may hold: it
+    is paid inline in the engine's process whatever the island backend."""
+    eng = Archipelago(n_islands=1, suite=FAST_SUITE, migration_interval=2,
+                      seed=11, backend="process", check_correctness=False,
+                      cascade_eta=2)
+    try:
+        eng.run(max_steps=2)
+        rung2 = eng.cascades["default"].rungs[2]
+        assert isinstance(rung2, InlineBackend)
+        paid = eng.cascade_totals()["evals"][MEASURED]
+        assert paid > 0 and rung2.n_evaluations == paid
+    finally:
+        eng.close()
 
 
 def test_engine_cascade_report_and_promote_fractions():

@@ -7,16 +7,21 @@ function, the scenario registry, and registry auto-scaling of the
 archipelago.  The socket service's own registry/heartbeat/fault paths live
 in tests/test_service.py."""
 import concurrent.futures as cf
+import os
 import pickle
 import threading
 
+import jax
 import pytest
 
 from repro.core import (Archipelago, BatchScorer, InlineBackend, KernelGenome,
                         ProcessBackend, ScoreCache, Scorer, make_backend,
                         register_suite, registered_suites, seed_genome,
                         suite_by_name, unregister_suite)
-from repro.core.evals import EvalSpec, ThreadBackend, evaluate_genome
+from repro.core.evals import (ElasticProcessPool, EvalSpec, ThreadBackend,
+                              evaluate_genome)
+from repro.core.evals.backends import make_process_executor
+from repro.core.evals.service import _worker_env
 from repro.core.perfmodel import BenchConfig
 
 FAST_SUITE = [BenchConfig("c4k", 8, 16, 16, 4096, causal=True),
@@ -393,6 +398,27 @@ def test_process_backend_bit_identical_to_inline():
         assert {n: p.breakdown() for n, p in a.profiles.items()} == \
             {n: p.breakdown() for n, p in b.profiles.items()}
     assert not want[-1].correct                  # the bf16 trap really fired
+
+
+@pytest.mark.parametrize("pool", ["fixed", "elastic"])
+def test_process_workers_hold_jax_to_cpu(pool, monkeypatch):
+    """Workers pay rung 0 and the interpreter check only; the chip belongs
+    to the parent.  Whatever environment they start from, their jax is
+    pinned to the CPU before first use."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    spec = EvalSpec.resolve(FAST_SUITE, False)
+    ex = (make_process_executor([spec], max_workers=1) if pool == "fixed"
+          else ElasticProcessPool((spec,), max_workers=1))
+    try:
+        assert ex.submit(os.getenv, "JAX_PLATFORMS").result() == "cpu"
+        assert ex.submit(jax.default_backend).result() == "cpu"
+    finally:
+        ex.shutdown()
+
+
+def test_service_worker_env_holds_jax_to_cpu(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    assert _worker_env()["JAX_PLATFORMS"] == "cpu"
 
 
 # -- the removed compat shim ---------------------------------------------------

@@ -117,8 +117,8 @@ def test_collective_bytes_semantics():
 def test_real_collectives_on_sharded_program():
     """End-to-end: psum over 1-device mesh emits no cross-device traffic, but
     the analyzer still parses the module without error."""
-    mesh = jax.make_mesh((1,), ("d",))
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32,
                              sharding=NamedSharding(mesh, P("d")))
     h = _analyze(lambda a: (a @ a.T).sum(), x)
