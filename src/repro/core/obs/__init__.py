@@ -25,13 +25,13 @@ import os
 import time as _time
 
 from .bus import ConsoleSink, EventBus, JournalSink
-from .metrics import REGISTRY, Counter, Gauge, Histogram, MetricsRegistry
+from .metrics import REGISTRY, Counter, Gauge, MetricsRegistry
 from .ring import DEFAULT_CAP, EventRing
 from .trace import current_trace, new_trace, use_trace
 
 __all__ = [
     "BUS", "REGISTRY", "ConsoleSink", "Counter", "DEFAULT_CAP", "EventBus",
-    "EventRing", "Gauge", "Histogram", "JournalSink", "MetricsRegistry",
+    "EventRing", "Gauge", "JournalSink", "MetricsRegistry",
     "close_journal", "current_trace", "enabled", "ensure_journal",
     "journal_path", "narrate", "new_trace", "publish", "set_enabled",
     "span", "use_trace",

@@ -1,4 +1,4 @@
-"""Process-wide metrics registry: counters, gauges, histograms with labels.
+"""Process-wide metrics registry: counters and gauges with labels.
 
 One registry (``REGISTRY``) owns every instrument.  Call sites hold the
 instrument object itself — ``self._hits = REGISTRY.counter("cache_hits",
@@ -13,8 +13,7 @@ Everything here is stdlib-only and cheap: a Counter increment is one
 across threads, so the instruments take a lock only where a read-modify-
 write races — Counter/Gauge use a plain lock-free add because every
 producer call site in this codebase already increments under its own
-structure lock or from a single thread; Histogram locks because it
-updates four fields together).
+structure lock or from a single thread).
 """
 from __future__ import annotations
 
@@ -57,39 +56,6 @@ class Gauge:
         return f"Gauge({self.name}{dict(self.labels)} = {self.value})"
 
 
-class Histogram:
-    """Count/total/min/max summary (no buckets — the report CLI derives
-    means; full distributions belong in the journal, not in memory)."""
-
-    __slots__ = ("name", "labels", "count", "total", "min", "max", "_lock")
-
-    def __init__(self, name: str, labels: tuple):
-        self.name = name
-        self.labels = labels
-        self.count = 0
-        self.total = 0.0
-        self.min = float("inf")
-        self.max = float("-inf")
-        self._lock = threading.Lock()
-
-    def observe(self, v: float) -> None:
-        with self._lock:
-            self.count += 1
-            self.total += v
-            if v < self.min:
-                self.min = v
-            if v > self.max:
-                self.max = v
-
-    @property
-    def mean(self) -> float:
-        return self.total / self.count if self.count else 0.0
-
-    def __repr__(self) -> str:  # pragma: no cover - debugging aid
-        return (f"Histogram({self.name}{dict(self.labels)} "
-                f"n={self.count} mean={self.mean:.4g})")
-
-
 class MetricsRegistry:
     """Get-or-create instrument allocator keyed by (name, sorted labels)."""
 
@@ -115,9 +81,6 @@ class MetricsRegistry:
     def gauge(self, name: str, **labels) -> Gauge:
         return self._get(Gauge, name, labels)
 
-    def histogram(self, name: str, **labels) -> Histogram:
-        return self._get(Histogram, name, labels)
-
     def instruments(self) -> Iterator:
         with self._lock:
             return iter(list(self._instruments.values()))
@@ -127,15 +90,8 @@ class MetricsRegistry:
         CLI, tests)."""
         out = []
         for inst in self.instruments():
-            row = {"kind": type(inst).__name__.lower(), "name": inst.name,
-                   "labels": dict(inst.labels)}
-            if isinstance(inst, Histogram):
-                row.update(count=inst.count, total=inst.total,
-                           min=(None if inst.count == 0 else inst.min),
-                           max=(None if inst.count == 0 else inst.max))
-            else:
-                row["value"] = inst.value
-            out.append(row)
+            out.append({"kind": type(inst).__name__.lower(), "name": inst.name,
+                        "labels": dict(inst.labels), "value": inst.value})
         return out
 
     def reset(self) -> None:

@@ -332,6 +332,7 @@ def flash_attention(
                                      "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name="flash_attention",
         )(q, k, v)
     else:
         grid = (B, Hq, nq)
@@ -350,6 +351,7 @@ def flash_attention(
                 dimension_semantics=("parallel", "parallel", "parallel"),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
+            name="flash_attention",
         )(q, k, v)
 
     o = o[:, :, :Sq, :]

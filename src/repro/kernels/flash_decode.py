@@ -115,5 +115,6 @@ def flash_decode(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_decode",
     )(valid_len.astype(jnp.int32), q4, k_cache, v_cache)
     return out.reshape(B, Hq, D)

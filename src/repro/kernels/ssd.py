@@ -131,5 +131,6 @@ def ssd_chunked(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd",
     )(xt, dtt, A.astype(jnp.float32), Bm[:, :, 0], Cm[:, :, 0])
     return y.transpose(0, 2, 1, 3), st

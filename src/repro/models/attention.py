@@ -59,55 +59,59 @@ def attn_apply(
     return_kv: bool = False, use_rope: bool = True,
 ):
     """Full-sequence attention (train / prefill).  x: (B, S, D)."""
-    prefix = "c_" if kv_source is not None else ""
-    h = norm_apply(x, p[prefix + "norm"], cfg).astype(compute_dtype)
-    if kv_source is None:
-        q, k, v = _project_qkv(h, p, cfg, compute_dtype)
-        S_kv = x.shape[1]
-    else:
-        B, S, D = h.shape
-        Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = (h @ p["c_wq"].astype(compute_dtype)).reshape(B, S, Hq, Dh)
-        mem = kv_source.astype(compute_dtype)
-        S_kv = mem.shape[1]
-        k = (mem @ p["c_wk"].astype(compute_dtype)).reshape(B, S_kv, Hkv, Dh)
-        v = (mem @ p["c_wv"].astype(compute_dtype)).reshape(B, S_kv, Hkv, Dh)
+    with jax.named_scope("attn"):
+        prefix = "c_" if kv_source is not None else ""
+        with jax.named_scope("qkv"):
+            h = norm_apply(x, p[prefix + "norm"], cfg).astype(compute_dtype)
+            if kv_source is None:
+                q, k, v = _project_qkv(h, p, cfg, compute_dtype)
+                S_kv = x.shape[1]
+            else:
+                B, S, D = h.shape
+                Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+                q = (h @ p["c_wq"].astype(compute_dtype)).reshape(B, S, Hq, Dh)
+                mem = kv_source.astype(compute_dtype)
+                S_kv = mem.shape[1]
+                k = (mem @ p["c_wk"].astype(compute_dtype)).reshape(B, S_kv, Hkv, Dh)
+                v = (mem @ p["c_wv"].astype(compute_dtype)).reshape(B, S_kv, Hkv, Dh)
 
-    if use_rope and kv_source is None:
-        S = x.shape[1]
-        qpos = jnp.arange(S) + pos_offset
-        q = rope_apply(q, qpos, cfg.rope_theta)
-        k = rope_apply(k, qpos, cfg.rope_theta)
+            if use_rope and kv_source is None:
+                S = x.shape[1]
+                qpos = jnp.arange(S) + pos_offset
+                q = rope_apply(q, qpos, cfg.rope_theta)
+                k = rope_apply(k, qpos, cfg.rope_theta)
 
-    # (B, H, S, D) layout for the kernels.  The constraint keeps batch on the
-    # DP axes AND heads on the model axis — a None batch dim here would FORCE
-    # replication and make XLA all-gather the global batch at every layer
-    # (the 16x activation-traffic bug found in the §Perf hillclimb).
-    # When the head count does NOT divide the model axis (qwen2: 28 heads on
-    # 16-way TP), fall back to SEQUENCE parallelism for Q/O: q-rows shard over
-    # the model axis and attend to gathered (small, GQA) K/V — otherwise the
-    # model axis sits idle and attention runs replicated (§Perf iter 2).
-    ba = batch_axes() or None
-    qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    head_ax = div_axis(cfg.n_heads)
-    seq_ax = None
-    if head_ax is None and kv_source is None:
-        seq_ax = div_axis(qt.shape[2])          # model axis over q rows
-    qt = shard(qt, ba, head_ax, seq_ax, None)
-    kv_ax = div_axis(cfg.n_kv_heads)
-    kt = shard(kt, ba, kv_ax, None, None)
-    vt = shard(vt, ba, kv_ax, None, None)
-    o = ops.attention(
-        qt, kt, vt,
-        causal=(causal and kv_source is None),
-        window=blk.window if kv_source is None else None,
-        softcap=cfg.attn_softcap, impl=impl, genome=genome)
-    B, S = x.shape[0], x.shape[1]
-    o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.head_dim)
-    out = o @ p[prefix + "wo"].astype(compute_dtype)
-    if cfg.post_norms and prefix == "":
-        out = norm_apply(out.astype(x.dtype), p["post_norm"], cfg)
-    result = x + out.astype(x.dtype)
+        # (B, H, S, D) layout for the kernels.  The constraint keeps batch on the
+        # DP axes AND heads on the model axis — a None batch dim here would FORCE
+        # replication and make XLA all-gather the global batch at every layer
+        # (the 16x activation-traffic bug found in the §Perf hillclimb).
+        # When the head count does NOT divide the model axis (qwen2: 28 heads on
+        # 16-way TP), fall back to SEQUENCE parallelism for Q/O: q-rows shard over
+        # the model axis and attend to gathered (small, GQA) K/V — otherwise the
+        # model axis sits idle and attention runs replicated (§Perf iter 2).
+        with jax.named_scope("kernel"):
+            ba = batch_axes() or None
+            qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            head_ax = div_axis(cfg.n_heads)
+            seq_ax = None
+            if head_ax is None and kv_source is None:
+                seq_ax = div_axis(qt.shape[2])          # model axis over q rows
+            qt = shard(qt, ba, head_ax, seq_ax, None)
+            kv_ax = div_axis(cfg.n_kv_heads)
+            kt = shard(kt, ba, kv_ax, None, None)
+            vt = shard(vt, ba, kv_ax, None, None)
+            o = ops.attention(
+                qt, kt, vt,
+                causal=(causal and kv_source is None),
+                window=blk.window if kv_source is None else None,
+                softcap=cfg.attn_softcap, impl=impl, genome=genome)
+            B, S = x.shape[0], x.shape[1]
+            o = o.transpose(0, 2, 1, 3).reshape(B, S, cfg.n_heads * cfg.head_dim)
+        with jax.named_scope("out"):
+            out = o @ p[prefix + "wo"].astype(compute_dtype)
+            if cfg.post_norms and prefix == "":
+                out = norm_apply(out.astype(x.dtype), p["post_norm"], cfg)
+            result = x + out.astype(x.dtype)
     if return_kv:
         return result, (kt, vt)      # (B, Hkv, S, Dh) — pre-cache layout
     return result
@@ -152,43 +156,53 @@ def attn_decode(
     """Single-token attention.  x: (B, D); pos: scalar absolute position."""
     B, D = x.shape
     Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    h = norm_apply(x, p["norm"], cfg).astype(compute_dtype)
-    q = (h @ p["wq"].astype(compute_dtype))
-    k = (h @ p["wk"].astype(compute_dtype))
-    v = (h @ p["wv"].astype(compute_dtype))
-    if cfg.qkv_bias:
-        q, k, v = (q + p["bq"].astype(compute_dtype),
-                   k + p["bk"].astype(compute_dtype),
-                   v + p["bv"].astype(compute_dtype))
-    q = q.reshape(B, Hq, Dh)
-    k = k.reshape(B, Hkv, Dh)
-    v = v.reshape(B, Hkv, Dh)
-    if use_rope:
-        q = rope_apply(q[:, None], pos, cfg.rope_theta)[:, 0]
-        k = rope_apply(k[:, None], pos, cfg.rope_theta)[:, 0]
+    with jax.named_scope("attn"):
+        with jax.named_scope("qkv"):
+            h = norm_apply(x, p["norm"], cfg).astype(compute_dtype)
+            q = (h @ p["wq"].astype(compute_dtype))
+            k = (h @ p["wk"].astype(compute_dtype))
+            v = (h @ p["wv"].astype(compute_dtype))
+            if cfg.qkv_bias:
+                q, k, v = (q + p["bq"].astype(compute_dtype),
+                           k + p["bk"].astype(compute_dtype),
+                           v + p["bv"].astype(compute_dtype))
+            q = q.reshape(B, Hq, Dh)
+            k = k.reshape(B, Hkv, Dh)
+            v = v.reshape(B, Hkv, Dh)
+            if use_rope:
+                q = rope_apply(q[:, None], pos, cfg.rope_theta)[:, 0]
+                k = rope_apply(k[:, None], pos, cfg.rope_theta)[:, 0]
 
-    Lc = cache["k"].shape[2]
-    slot = (pos % Lc) if blk.window else pos
-    kc = jax.lax.dynamic_update_slice_in_dim(
-        cache["k"], k[:, :, None].astype(cache["k"].dtype), slot, axis=2)
-    vc = jax.lax.dynamic_update_slice_in_dim(
-        cache["v"], v[:, :, None].astype(cache["v"].dtype), slot, axis=2)
-    valid = jnp.minimum(pos + 1, Lc)
-    valid_len = jnp.full((B,), valid, jnp.int32)
-    o = ops.decode_attention(q, kc, vc, valid_len, softcap=cfg.attn_softcap,
-                             impl=impl, genome=genome)
-    out = o.reshape(B, Hq * Dh) @ p["wo"].astype(compute_dtype)
-    if cfg.post_norms:
-        out = norm_apply(out.astype(x.dtype), p["post_norm"], cfg)
-    x = x + out.astype(x.dtype)
+        Lc = cache["k"].shape[2]
+        with jax.named_scope("kv_cache"):
+            slot = (pos % Lc) if blk.window else pos
+            kc = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k[:, :, None].astype(cache["k"].dtype), slot, axis=2)
+            vc = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v[:, :, None].astype(cache["v"].dtype), slot, axis=2)
+        with jax.named_scope("kernel"):
+            valid = jnp.minimum(pos + 1, Lc)
+            valid_len = jnp.full((B,), valid, jnp.int32)
+            o = ops.decode_attention(q, kc, vc, valid_len, softcap=cfg.attn_softcap,
+                                     impl=impl, genome=genome)
+        with jax.named_scope("out"):
+            out = o.reshape(B, Hq * Dh) @ p["wo"].astype(compute_dtype)
+            if cfg.post_norms:
+                out = norm_apply(out.astype(x.dtype), p["post_norm"], cfg)
+            x = x + out.astype(x.dtype)
 
-    if cross_cache is not None:
-        hc = norm_apply(x, p["c_norm"], cfg).astype(compute_dtype)
-        qc = (hc @ p["c_wq"].astype(compute_dtype)).reshape(B, Hq, Dh)
-        vl = jnp.full((B,), enc_len, jnp.int32)
-        oc = ops.decode_attention(qc, cross_cache["k"].astype(compute_dtype),
-                                  cross_cache["v"].astype(compute_dtype), vl,
-                                  softcap=cfg.attn_softcap, impl=impl, genome=genome)
-        x = x + (oc.reshape(B, Hq * Dh) @ p["c_wo"].astype(compute_dtype)).astype(x.dtype)
+        if cross_cache is not None:
+            with jax.named_scope("qkv"):
+                hc = norm_apply(x, p["c_norm"], cfg).astype(compute_dtype)
+                qc = (hc @ p["c_wq"].astype(compute_dtype)).reshape(B, Hq, Dh)
+            with jax.named_scope("kernel"):
+                vl = jnp.full((B,), enc_len, jnp.int32)
+                oc = ops.decode_attention(qc, cross_cache["k"].astype(compute_dtype),
+                                          cross_cache["v"].astype(compute_dtype), vl,
+                                          softcap=cfg.attn_softcap, impl=impl,
+                                          genome=genome)
+            with jax.named_scope("out"):
+                x = x + (oc.reshape(B, Hq * Dh)
+                         @ p["c_wo"].astype(compute_dtype)).astype(x.dtype)
 
     return x, {"k": kc, "v": vc}

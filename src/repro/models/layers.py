@@ -81,23 +81,24 @@ def mlp_init(key, cfg: ArchConfig, blk: Block):
 
 
 def mlp_apply(x, p, cfg: ArchConfig, blk: Block, compute_dtype):
-    h = norm_apply(x, p["norm"], cfg)
-    h = h.astype(compute_dtype)
-    if blk.mlp == "gated_silu":
-        a = jax.nn.silu(h @ p["w_gate"].astype(compute_dtype))
-        h = (a * (h @ p["w_up"].astype(compute_dtype))) @ p["w_down"].astype(compute_dtype)
-    elif blk.mlp == "gated_gelu":
-        a = jax.nn.gelu(h @ p["w_gate"].astype(compute_dtype), approximate=True)
-        h = (a * (h @ p["w_up"].astype(compute_dtype))) @ p["w_down"].astype(compute_dtype)
-    elif blk.mlp == "squared_relu":
-        a = jax.nn.relu(h @ p["w_up"].astype(compute_dtype))
-        h = (a * a) @ p["w_down"].astype(compute_dtype)
-    elif blk.mlp == "relu":
-        a = jax.nn.relu(h @ p["w_up"].astype(compute_dtype))
-        h = a @ p["w_down"].astype(compute_dtype)
-    if cfg.post_norms:
-        h = norm_apply(h, p["post_norm"], cfg)
-    return x + h.astype(x.dtype)
+    with jax.named_scope("mlp"):
+        h = norm_apply(x, p["norm"], cfg)
+        h = h.astype(compute_dtype)
+        if blk.mlp == "gated_silu":
+            a = jax.nn.silu(h @ p["w_gate"].astype(compute_dtype))
+            h = (a * (h @ p["w_up"].astype(compute_dtype))) @ p["w_down"].astype(compute_dtype)
+        elif blk.mlp == "gated_gelu":
+            a = jax.nn.gelu(h @ p["w_gate"].astype(compute_dtype), approximate=True)
+            h = (a * (h @ p["w_up"].astype(compute_dtype))) @ p["w_down"].astype(compute_dtype)
+        elif blk.mlp == "squared_relu":
+            a = jax.nn.relu(h @ p["w_up"].astype(compute_dtype))
+            h = (a * a) @ p["w_down"].astype(compute_dtype)
+        elif blk.mlp == "relu":
+            a = jax.nn.relu(h @ p["w_up"].astype(compute_dtype))
+            h = a @ p["w_down"].astype(compute_dtype)
+        if cfg.post_norms:
+            h = norm_apply(h, p["post_norm"], cfg)
+        return x + h.astype(x.dtype)
 
 
 def logit_softcap(logits, cap: float):

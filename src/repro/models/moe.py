@@ -62,35 +62,36 @@ def moe_apply(x, p, cfg: ArchConfig, compute_dtype, return_aux: bool = False):
     locality enforced manually — GSPMD replicates data-dependent scatters),
     leaving the model axis on auto so expert-weight TP/EP still partitions
     inside.  Falls back to the GSPMD global path off-mesh / non-divisible."""
-    mesh = get_mesh()
-    ba = batch_axes()
-    B, S = x.shape[0], x.shape[1]
-    dp = 1
-    for a in ba:
-        dp *= mesh.shape[a]
-    # shard_map replicates expert weights across DP (gathered once per call):
-    # profitable only when enough tokens amortize it — decode steps (a few
-    # tokens/shard) measured 0.3x WORSE, so they stay on the global path.
-    tokens_per_shard = B * S // max(dp, 1)
-    if (mesh is None or not ba or B % dp != 0 or return_aux
-            or tokens_per_shard < 256):
-        # below the amortization threshold grouping also hurts (the grouped
-        # rank-4 expert GEMMs make GSPMD gather W): plain global dispatch
-        return _moe_apply_global(x, p, cfg, compute_dtype, return_aux,
-                                 groups=1)
+    with jax.named_scope("moe"):
+        mesh = get_mesh()
+        ba = batch_axes()
+        B, S = x.shape[0], x.shape[1]
+        dp = 1
+        for a in ba:
+            dp *= mesh.shape[a]
+        # shard_map replicates expert weights across DP (gathered once per call):
+        # profitable only when enough tokens amortize it — decode steps (a few
+        # tokens/shard) measured 0.3x WORSE, so they stay on the global path.
+        tokens_per_shard = B * S // max(dp, 1)
+        if (mesh is None or not ba or B % dp != 0 or return_aux
+                or tokens_per_shard < 256):
+            # below the amortization threshold grouping also hurts (the grouped
+            # rank-4 expert GEMMs make GSPMD gather W): plain global dispatch
+            return _moe_apply_global(x, p, cfg, compute_dtype, return_aux,
+                                     groups=1)
 
-    from jax.sharding import PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-    fn = jax.shard_map(
-        lambda xl, pl: _moe_apply_global(xl, pl, cfg, compute_dtype, False,
-                                         local=True),
-        mesh=mesh,
-        in_specs=(P(ba, None, None), P()),
-        out_specs=P(ba, None, None),
-        axis_names=frozenset(ba),            # manual over DP; model stays auto
-        check_vma=False,
-    )
-    return fn(x, p)
+        fn = jax.shard_map(
+            lambda xl, pl: _moe_apply_global(xl, pl, cfg, compute_dtype, False,
+                                             local=True),
+            mesh=mesh,
+            in_specs=(P(ba, None, None), P()),
+            out_specs=P(ba, None, None),
+            axis_names=frozenset(ba),            # manual over DP; model stays auto
+            check_vma=False,
+        )
+        return fn(x, p)
 
 
 def _moe_apply_global(x, p, cfg: ArchConfig, compute_dtype,

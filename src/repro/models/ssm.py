@@ -57,34 +57,35 @@ def _gated_norm(y, z, w, eps):
 
 def mamba_apply(x, p, cfg: ArchConfig, compute_dtype, impl=None):
     """Full-sequence path (train / prefill).  x: (B, S, D)."""
-    s, d_in, H, conv_dim = _dims(cfg)
-    B, S, D = x.shape
-    h = norm_apply(x, p["norm"], cfg).astype(compute_dtype)
-    proj = h @ p["in_proj"].astype(compute_dtype)
-    z, xv, Bv, Cv, dt = _split_proj(proj, cfg)
+    with jax.named_scope("ssm"):
+        s, d_in, H, conv_dim = _dims(cfg)
+        B, S, D = x.shape
+        h = norm_apply(x, p["norm"], cfg).astype(compute_dtype)
+        proj = h @ p["in_proj"].astype(compute_dtype)
+        z, xv, Bv, Cv, dt = _split_proj(proj, cfg)
 
-    # depthwise causal conv over [x|B|C]
-    xbc = jnp.concatenate([xv, Bv, Cv], axis=-1)                       # (B,S,conv_dim)
-    K = s.conv_kernel
-    pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
-    conv = sum(pad[:, i:i + S] * p["conv_w"][i].astype(compute_dtype) for i in range(K))
-    conv = jax.nn.silu(conv + p["conv_b"].astype(compute_dtype))
-    xv, Bv, Cv = jnp.split(conv, [d_in, d_in + s.n_groups * s.d_state], axis=-1)
+        # depthwise causal conv over [x|B|C]
+        xbc = jnp.concatenate([xv, Bv, Cv], axis=-1)                       # (B,S,conv_dim)
+        K = s.conv_kernel
+        pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+        conv = sum(pad[:, i:i + S] * p["conv_w"][i].astype(compute_dtype) for i in range(K))
+        conv = jax.nn.silu(conv + p["conv_b"].astype(compute_dtype))
+        xv, Bv, Cv = jnp.split(conv, [d_in, d_in + s.n_groups * s.d_state], axis=-1)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])        # (B,S,H)
-    A = -jnp.exp(p["A_log"])                                           # (H,)
-    xh = xv.reshape(B, S, H, s.head_dim)
-    Bm = Bv.reshape(B, S, s.n_groups, s.d_state)
-    Cm = Cv.reshape(B, S, s.n_groups, s.d_state)
-    y, state = ops.ssd(xh, dt, A, Bm, Cm, chunk=s.chunk, impl=impl)
-    y = y + p["D_skip"].astype(y.dtype)[None, None, :, None] * xh
-    y = y.reshape(B, S, d_in)
-    y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps).astype(compute_dtype)
-    out = y @ p["out_proj"].astype(compute_dtype)
-    # decode-resumable cache pieces: final ssm state + conv tail
-    conv_tail = xbc[:, -(K - 1):, :] if S >= K - 1 else jnp.pad(
-        xbc, ((0, 0), (K - 1 - S, 0), (0, 0)))
-    return x + out.astype(x.dtype), {"ssm": state, "conv": conv_tail.astype(jnp.float32)}
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])        # (B,S,H)
+        A = -jnp.exp(p["A_log"])                                           # (H,)
+        xh = xv.reshape(B, S, H, s.head_dim)
+        Bm = Bv.reshape(B, S, s.n_groups, s.d_state)
+        Cm = Cv.reshape(B, S, s.n_groups, s.d_state)
+        y, state = ops.ssd(xh, dt, A, Bm, Cm, chunk=s.chunk, impl=impl)
+        y = y + p["D_skip"].astype(y.dtype)[None, None, :, None] * xh
+        y = y.reshape(B, S, d_in)
+        y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps).astype(compute_dtype)
+        out = y @ p["out_proj"].astype(compute_dtype)
+        # decode-resumable cache pieces: final ssm state + conv tail
+        conv_tail = xbc[:, -(K - 1):, :] if S >= K - 1 else jnp.pad(
+            xbc, ((0, 0), (K - 1 - S, 0), (0, 0)))
+        return x + out.astype(x.dtype), {"ssm": state, "conv": conv_tail.astype(jnp.float32)}
 
 
 def mamba_cache_init(cfg: ArchConfig, batch: int):
@@ -97,28 +98,29 @@ def mamba_cache_init(cfg: ArchConfig, batch: int):
 
 def mamba_decode(x, p, cache, cfg: ArchConfig, compute_dtype):
     """Single-token path.  x: (B, D); cache: {"ssm": (B,H,P,N), "conv": (B,K-1,C)}."""
-    s, d_in, H, conv_dim = _dims(cfg)
-    B, D = x.shape
-    h = norm_apply(x, p["norm"], cfg).astype(compute_dtype)
-    proj = h @ p["in_proj"].astype(compute_dtype)
-    z, xv, Bv, Cv, dt = _split_proj(proj, cfg)
+    with jax.named_scope("ssm"):
+        s, d_in, H, conv_dim = _dims(cfg)
+        B, D = x.shape
+        h = norm_apply(x, p["norm"], cfg).astype(compute_dtype)
+        proj = h @ p["in_proj"].astype(compute_dtype)
+        z, xv, Bv, Cv, dt = _split_proj(proj, cfg)
 
-    xbc = jnp.concatenate([xv, Bv, Cv], axis=-1)                       # (B, conv_dim)
-    K = s.conv_kernel
-    hist = jnp.concatenate([cache["conv"].astype(compute_dtype), xbc[:, None]], axis=1)
-    conv = jnp.einsum("bkc,kc->bc", hist, p["conv_w"].astype(compute_dtype))
-    conv = jax.nn.silu(conv + p["conv_b"].astype(compute_dtype))
-    xv, Bv, Cv = jnp.split(conv, [d_in, d_in + s.n_groups * s.d_state], axis=-1)
+        xbc = jnp.concatenate([xv, Bv, Cv], axis=-1)                       # (B, conv_dim)
+        K = s.conv_kernel
+        hist = jnp.concatenate([cache["conv"].astype(compute_dtype), xbc[:, None]], axis=1)
+        conv = jnp.einsum("bkc,kc->bc", hist, p["conv_w"].astype(compute_dtype))
+        conv = jax.nn.silu(conv + p["conv_b"].astype(compute_dtype))
+        xv, Bv, Cv = jnp.split(conv, [d_in, d_in + s.n_groups * s.d_state], axis=-1)
 
-    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])        # (B,H)
-    A = -jnp.exp(p["A_log"])
-    xh = xv.reshape(B, H, s.head_dim)
-    Bm = Bv.reshape(B, s.n_groups, s.d_state)
-    Cm = Cv.reshape(B, s.n_groups, s.d_state)
-    y, new_state = ops.ssd_decode(xh, dt, A, Bm, Cm, cache["ssm"])
-    y = y + p["D_skip"].astype(y.dtype)[None, :, None] * xh
-    y = y.reshape(B, d_in)
-    y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps).astype(compute_dtype)
-    out = y @ p["out_proj"].astype(compute_dtype)
-    new_cache = {"ssm": new_state, "conv": hist[:, 1:].astype(jnp.float32)}
-    return x + out.astype(x.dtype), new_cache
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])        # (B,H)
+        A = -jnp.exp(p["A_log"])
+        xh = xv.reshape(B, H, s.head_dim)
+        Bm = Bv.reshape(B, s.n_groups, s.d_state)
+        Cm = Cv.reshape(B, s.n_groups, s.d_state)
+        y, new_state = ops.ssd_decode(xh, dt, A, Bm, Cm, cache["ssm"])
+        y = y + p["D_skip"].astype(y.dtype)[None, :, None] * xh
+        y = y.reshape(B, d_in)
+        y = _gated_norm(y, z, p["gate_norm"], cfg.norm_eps).astype(compute_dtype)
+        out = y @ p["out_proj"].astype(compute_dtype)
+        new_cache = {"ssm": new_state, "conv": hist[:, 1:].astype(jnp.float32)}
+        return x + out.astype(x.dtype), new_cache

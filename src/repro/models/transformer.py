@@ -8,6 +8,30 @@ Public API:
   lm_loss(params, cfg, batch, ...)               -> scalar
   prefill(params, cfg, tokens, max_len, ...)     -> (last_logits, cache)
   decode_step(params, cfg, cache, token, ...)    -> (logits, cache)
+
+Every layer boundary of the model step carries a ``jax.named_scope``.  The
+scopes are compile-time labels: they land in each op's metadata (the
+``op_name`` of the HLO, ``tf_op`` in a profiler trace) and change nothing
+that runs.  One fixed set of names, nested as shown:
+
+  embed            token embedding and its scale (``_embed``)
+  layers           the ``lax.scan`` over the layer stack; ops under it and in
+                   no scope below are the loop's own moves: slicing weights
+                   and caches out of the stack, stacking outputs (copies the
+                   compiler adds around the loop may carry no op name)
+    attn           the attention sublayer (``attn_apply``, ``attn_decode``)
+      qkv          norm, Q/K/V projections, biases, RoPE
+      kernel       the attention kernel call with its layout transposes
+      kv_cache     decode: the write of the new key and value into the cache
+      out          output projection, post-norm, residual
+    mlp            ``mlp_apply``: norm, matmuls, activation, residual
+    moe            ``moe_apply``
+    ssm            ``mamba_apply``, ``mamba_decode``
+  kv_cache         prefill: arranging K/V into the decode cache
+  head             final norm, LM head, softcap (``_head``)
+
+The Pallas kernels carry stable names of their own (``flash_attention``,
+``flash_decode``, ``ssd``), which also name their custom calls in the HLO.
 """
 from __future__ import annotations
 
@@ -137,7 +161,8 @@ def _run_stack(params_stack, x, cfg: ArchConfig, pattern, *, causal, compute_dty
     # GEMM outputs round-trip HBM) and inflated live temp bytes; full
     # per-period remat is the better point on this memory-bound Pareto.
     body = jax.checkpoint(period) if (remat and not collect) else period
-    x, caches = jax.lax.scan(body, x, params_stack)
+    with jax.named_scope("layers"):
+        x, caches = jax.lax.scan(body, x, params_stack)
     return x, caches
 
 
@@ -147,13 +172,14 @@ def _run_stack(params_stack, x, cfg: ArchConfig, pattern, *, causal, compute_dty
 
 
 def _embed(params, cfg: ArchConfig, tokens, prefix_embeds=None, compute_dtype=jnp.bfloat16):
-    x = params["embed"].astype(compute_dtype)[tokens]
-    if cfg.scale_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
-    if prefix_embeds is not None and cfg.n_prefix_embeds:
-        P = min(cfg.n_prefix_embeds, x.shape[1])
-        x = jax.lax.dynamic_update_slice(
-            x, prefix_embeds[:, :P].astype(compute_dtype), (0, 0, 0))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(compute_dtype)[tokens]
+        if cfg.scale_embeddings:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
+        if prefix_embeds is not None and cfg.n_prefix_embeds:
+            P = min(cfg.n_prefix_embeds, x.shape[1])
+            x = jax.lax.dynamic_update_slice(
+                x, prefix_embeds[:, :P].astype(compute_dtype), (0, 0, 0))
     return x
 
 
@@ -166,23 +192,24 @@ def _head(params, cfg: ArchConfig, x, compute_dtype, pad_vocab: bool = False):
     kept through the loss (slicing would force a re-replication)."""
     from repro.distributed.context import axis_size
 
-    x = norm_apply(x, params["final_norm"], cfg).astype(compute_dtype)
-    w = (params["embed"].astype(compute_dtype).T if cfg.tie_embeddings
-         else params["lm_head"].astype(compute_dtype))
-    V = cfg.vocab_size
-    pad = 0
-    if pad_vocab:
-        mdl = axis_size("model")
-        if mdl > 1 and V % mdl:
-            pad = (-V) % mdl
-            w = jnp.pad(w, ((0, 0), (0, pad)))
-    logits = x @ w
-    logits = logit_softcap(logits.astype(jnp.float32), cfg.logit_softcap)
-    if pad:
-        neg = jnp.full((pad,), -1e30, jnp.float32)
-        logits = logits.at[..., V:].set(neg)
-    return shard(logits, batch_axes() or None, *([None] * (logits.ndim - 2)),
-                 div_axis(V + pad))
+    with jax.named_scope("head"):
+        x = norm_apply(x, params["final_norm"], cfg).astype(compute_dtype)
+        w = (params["embed"].astype(compute_dtype).T if cfg.tie_embeddings
+             else params["lm_head"].astype(compute_dtype))
+        V = cfg.vocab_size
+        pad = 0
+        if pad_vocab:
+            mdl = axis_size("model")
+            if mdl > 1 and V % mdl:
+                pad = (-V) % mdl
+                w = jnp.pad(w, ((0, 0), (0, pad)))
+        logits = x @ w
+        logits = logit_softcap(logits.astype(jnp.float32), cfg.logit_softcap)
+        if pad:
+            neg = jnp.full((pad,), -1e30, jnp.float32)
+            logits = logits.at[..., V:].set(neg)
+        return shard(logits, batch_axes() or None, *([None] * (logits.ndim - 2)),
+                     div_axis(V + pad))
 
 
 # ---------------------------------------------------------------------------
@@ -257,9 +284,10 @@ def prefill(params, cfg: ArchConfig, tokens, max_len: int, *,
         c = raw[f"pos{i}"]
         if blk.kind == "attn":
             kt, vt = c["kv"]                      # (n_per, B, Hkv, S, Dh)
-            arranged = jax.vmap(
-                lambda k, v: tuple(attn_mod.cache_from_prefill(k, v, blk, max_len).values()
-                                   ))(kt.astype(cache_dtype), vt.astype(cache_dtype))
+            with jax.named_scope("kv_cache"):
+                arranged = jax.vmap(
+                    lambda k, v: tuple(attn_mod.cache_from_prefill(k, v, blk, max_len).values()
+                                       ))(kt.astype(cache_dtype), vt.astype(cache_dtype))
             entry["k"], entry["v"] = arranged
             if blk.cross_attn and cfg.enc_dec:
                 entry["cross"] = _cross_cache(params["dec"], cfg, i, enc_out, compute_dtype)
@@ -316,10 +344,7 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int, *,
 def decode_step(params, cfg: ArchConfig, cache, token, *,
                 compute_dtype=jnp.bfloat16, impl=None, genome=None):
     """One token for every sequence in the batch.  token: (B,) int32."""
-    B = token.shape[0]
-    x = params["embed"].astype(compute_dtype)[token]
-    if cfg.scale_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, compute_dtype)
+    x = _embed(params, cfg, token, compute_dtype=compute_dtype)
     pos = cache["pos"]
     enc_len = cache.get("enc_len", 0)
 
@@ -347,7 +372,8 @@ def decode_step(params, cfg: ArchConfig, cache, token, *,
                 x = mlp_apply(x[:, None], p["mlp"], cfg, cfg.pattern[i], compute_dtype)[:, 0]
         return x, new_c
 
-    x, new_layers = jax.lax.scan(period, x, (params["dec"], cache["layers"]))
+    with jax.named_scope("layers"):
+        x, new_layers = jax.lax.scan(period, x, (params["dec"], cache["layers"]))
     logits = _head(params, cfg, x, compute_dtype)
     new_cache = dict(cache, pos=pos + 1, layers=new_layers)
     return logits, new_cache

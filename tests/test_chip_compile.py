@@ -9,6 +9,7 @@ import, so every pytest worker collects the same tests.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.search_space import seed_genome
+from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ops import DEFAULT_ATTN_GENOME
@@ -50,6 +52,7 @@ def _compile(fn, sharding, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 BF16 = jnp.bfloat16
@@ -90,3 +93,23 @@ def test_ssd_compiles_at_mamba2_780m_widths(one_chip):
     _compile(functools.partial(ssd_chunked, chunk=256, block_heads=8),
              one_chip, ((B, L, H, P), BF16), ((B, L, H), jnp.float32),
              ((H,), jnp.float32), ((B, L, 1, N), BF16), ((B, L, 1, N), BF16))
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention", "flash_decode"])
+def test_kernel_custom_calls_carry_the_kernel_name(one_chip, kernel):
+    """A trace names each kernel by its custom call's instruction name, and
+    the benchmark finds the kernel by that name's prefix.  Called as the
+    model calls it, inside its layer scopes, the name is still the kernel's."""
+    def step(q, k, v, *valid_len):
+        with jax.named_scope("attn"), jax.named_scope("kernel"):
+            if valid_len:
+                return ops.decode_attention(q, k, v, *valid_len, impl="pallas")
+            return ops.attention(q, k, v, causal=True, impl="pallas")
+
+    kv = ((2, 4, 1024, 128), BF16)
+    shapes = ([((2, 8, 128), BF16), kv, kv, ((2,), jnp.int32)] if kernel == "flash_decode"
+              else [((2, 8, 1024, 128), BF16), kv, kv])
+    text = _compile(step, one_chip, *shapes)
+    names = [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line
+             for m in [re.search(r"%([\w.\-]+) = .*custom-call\(", line)] if m]
+    assert names and all(n.startswith(kernel) for n in names), names
