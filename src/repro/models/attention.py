@@ -153,7 +153,9 @@ def attn_decode(
     pos, compute_dtype, cross_cache=None, enc_len: Optional[int] = None,
     impl: Optional[str] = None, genome: Optional[dict] = None, use_rope: bool = True,
 ):
-    """Single-token attention.  x: (B, D); pos: scalar absolute position."""
+    """Single-token attention.  x: (B, D); pos: scalar absolute position.
+    Returns (x, the layer's cache with the new key and value written, the
+    slot they went to)."""
     B, D = x.shape
     Hq, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     with jax.named_scope("attn"):
@@ -205,4 +207,4 @@ def attn_decode(
                 x = x + (oc.reshape(B, Hq * Dh)
                          @ p["c_wo"].astype(compute_dtype)).astype(x.dtype)
 
-    return x, {"k": kc, "v": vc}
+    return x, {"k": kc, "v": vc}, slot

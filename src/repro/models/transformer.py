@@ -17,12 +17,14 @@ that runs.  One fixed set of names, nested as shown:
   embed            token embedding and its scale (``_embed``)
   layers           the ``lax.scan`` over the layer stack; ops under it and in
                    no scope below are the loop's own moves: slicing weights
-                   and caches out of the stack, stacking outputs (copies the
-                   compiler adds around the loop may carry no op name)
+                   and, in decode, each layer's cache out of the stacked
+                   cache the loop carries (copies the compiler adds around
+                   the loop may carry no op name)
     attn           the attention sublayer (``attn_apply``, ``attn_decode``)
       qkv          norm, Q/K/V projections, biases, RoPE
       kernel       the attention kernel call with its layout transposes
-      kv_cache     decode: the write of the new key and value into the cache
+      kv_cache     decode: the new key and value, written into the layer's
+                   cache and, one row each, into the carried stack
       out          output projection, post-norm, residual
     mlp            ``mlp_apply``: norm, matmuls, activation, residual
     moe            ``moe_apply``
@@ -343,37 +345,53 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int, *,
 
 def decode_step(params, cfg: ArchConfig, cache, token, *,
                 compute_dtype=jnp.bfloat16, impl=None, genome=None):
-    """One token for every sequence in the batch.  token: (B,) int32."""
+    """One token for every sequence in the batch.  token: (B,) int32.
+
+    The stacked cache rides the layer scan as its carry: each layer reads its
+    own cache out of the stack and writes back only what changed (one key and
+    value row; the whole small mamba state), so a donated cache is updated in
+    place and never rebuilt."""
     x = _embed(params, cfg, token, compute_dtype=compute_dtype)
     pos = cache["pos"]
     enc_len = cache.get("enc_len", 0)
 
-    def period(x, xs):
-        pslice, cslice = xs
-        new_c = {}
+    def period(carry, xs):
+        x, layers = carry
+        pslice, li = xs
+        layers = dict(layers)
         for i, blk in enumerate(cfg.pattern):
-            p, c = pslice[f"pos{i}"], cslice[f"pos{i}"]
+            p, stack = pslice[f"pos{i}"], dict(layers[f"pos{i}"])
+            c = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, li, keepdims=False), stack)
             if blk.kind == "attn":
-                x, kv = attn_mod.attn_decode(
+                x, kv, slot = attn_mod.attn_decode(
                     x, p["attn"], c, cfg, blk, pos=pos, compute_dtype=compute_dtype,
                     cross_cache=c.get("cross"), enc_len=enc_len,
                     impl=impl, genome=genome)
-                ncd = dict(kv)
-                if "cross" in c:
-                    ncd["cross"] = c["cross"]
-                new_c[f"pos{i}"] = ncd
+                # the rows are read back from the layer's updated cache, so
+                # the write into the stack depends on the slice that read it:
+                # the compiler orders the two and updates the stack in place
+                with jax.named_scope("attn"), jax.named_scope("kv_cache"):
+                    for n in ("k", "v"):
+                        row = jax.lax.dynamic_slice_in_dim(kv[n], slot, 1, axis=2)
+                        stack[n] = jax.lax.dynamic_update_slice(
+                            stack[n], row[None], (li, 0, 0, slot, 0))
             elif blk.kind == "mamba":
                 x, mc = ssm_mod.mamba_decode(x, p["mamba"], c["mamba"],
                                              cfg, compute_dtype)
-                new_c[f"pos{i}"] = {"mamba": mc}
+                stack["mamba"] = jax.tree_util.tree_map(
+                    lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, li, 0),
+                    stack["mamba"], mc)
             if blk.mlp == "moe":
                 x = moe_mod.moe_apply(x[:, None], p["moe"], cfg, compute_dtype)[:, 0]
             elif blk.mlp != "none":
                 x = mlp_apply(x[:, None], p["mlp"], cfg, cfg.pattern[i], compute_dtype)[:, 0]
-        return x, new_c
+            layers[f"pos{i}"] = stack
+        return (x, layers), None
 
     with jax.named_scope("layers"):
-        x, new_layers = jax.lax.scan(period, x, (params["dec"], cache["layers"]))
+        (x, new_layers), _ = jax.lax.scan(
+            period, (x, cache["layers"]), (params["dec"], jnp.arange(cfg.n_periods)))
     logits = _head(params, cfg, x, compute_dtype)
     new_cache = dict(cache, pos=pos + 1, layers=new_layers)
     return logits, new_cache

@@ -7,6 +7,7 @@ primitive Mosaic cannot lower.  Each test asserts the Mosaic kernel is in
 the compiled program.  The topology is described inside a fixture, never at
 import, so every pytest worker collects the same tests.
 """
+import dataclasses
 import functools
 import os
 import re
@@ -16,12 +17,15 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.registry import ARCHS
 from repro.core.search_space import seed_genome
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.ops import DEFAULT_ATTN_GENOME
 from repro.kernels.ssd import ssd_chunked
+from repro.launch.serve import make_serve_step
+from repro.models import init_decode_cache, init_params
 
 
 @pytest.fixture(scope="module")
@@ -113,3 +117,41 @@ def test_kernel_custom_calls_carry_the_kernel_name(one_chip, kernel):
     names = [m.group(1) for line in text.splitlines() if "tpu_custom_call" in line
              for m in [re.search(r"%([\w.\-]+) = .*custom-call\(", line)] if m]
     assert names and all(n.startswith(kernel) for n in names), names
+
+
+V5E_HBM = 15.75 * 2**30          # what the compiler lets a v5e program plan
+
+
+@pytest.mark.parametrize("sessions", [18, 32])
+def test_qwen2_decode_step_updates_its_stacked_cache_in_place(one_chip, sessions):
+    """qwen2-7b's decode step at its widths, 8 layers, against 16384 slots
+    a session, bf16, the cache donated: the Mosaic ``flash_decode`` serves
+    it, nothing copies a whole stacked K or V cache, and the plan fits one
+    chip at 32 sessions (a step that rebuilt the stack needed it twice)."""
+    cfg = dataclasses.replace(ARCHS["qwen2-7b"], n_layers=8, remat=False)
+    slots = 16384
+
+    def sds(tree, dtype=None):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    params = sds(jax.eval_shape(functools.partial(init_params, cfg),
+                                jax.random.key(0)), BF16)
+    cache = sds(jax.eval_shape(functools.partial(init_decode_cache, cfg, sessions,
+                                                 slots)))
+    step = jax.jit(make_serve_step(cfg, BF16, impl="pallas"), donate_argnums=(1,))
+    compiled = step.lower(params, cache, jax.ShapeDtypeStruct(
+        (sessions,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert re.search(r"%flash_decode[\w.]* = .*tpu_custom_call", text)
+    stacked = f"bf16[{cfg.n_layers},{sessions},{cfg.n_kv_heads},{slots},{cfg.head_dim}]"
+    whole = [line.strip()[:120] for line in text.splitlines()
+             if re.search(r"= " + re.escape(stacked) + r"\S* (copy|fusion)\(", line)]
+    assert not whole, whole
+    mem = compiled.memory_analysis()
+    planned = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+               - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    # the donated K and V (and the position) are the outputs' buffers
+    assert mem.alias_size_in_bytes >= 2 * jnp.dtype(BF16).itemsize * (
+        cfg.n_layers * sessions * cfg.n_kv_heads * slots * cfg.head_dim)
+    assert planned < V5E_HBM

@@ -58,3 +58,28 @@ def test_decode_cache_isolated_across_batch(tiny_archs):
     lb, _ = prefill(params, cfg, b, 16, compute_dtype=jnp.float32,
                     cache_dtype=jnp.float32)
     np.testing.assert_allclose(la[0], lb[0], atol=1e-5)
+
+
+@pytest.mark.parametrize("name", ["gemma2-27b", "h2o-danube-3-4b", "mixtral-8x22b"])
+@pytest.mark.parametrize("S", [6, 20])
+def test_windowed_ring_decodes_past_its_window(name, S, tiny_archs):
+    """A sliding-window layer keeps a ring of its window's slots: decoding
+    well past the window wraps the ring (more than once from the short
+    prompt; from a prompt longer than the window, prefill's rolled ring),
+    and each donated step writes its row at ``pos % window`` in place."""
+    cfg = tiny_archs[name]
+    window = max(b.window or 0 for b in cfg.pattern)
+    B, T = 2, 2 * window + 4 - S
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks = jnp.asarray(np.random.default_rng(11).integers(0, cfg.vocab_size, (B, S + T)),
+                       jnp.int32)
+    full = lm_logits(params, cfg, toks, compute_dtype=jnp.float32)
+    _, cache = prefill(params, cfg, toks[:, :S], S + T, compute_dtype=jnp.float32,
+                       cache_dtype=jnp.float32)
+    step = jax.jit(lambda c, t: decode_step(params, cfg, c, t, compute_dtype=jnp.float32),
+                   donate_argnums=(0,))
+    for t in range(T - 1):
+        logits, cache = step(cache, toks[:, S + t])
+        np.testing.assert_allclose(logits, full[:, S + t], atol=2e-3, rtol=2e-3,
+                                   err_msg=f"{name}: position {S + t}")
+    assert int(cache["pos"]) == S + T - 1 > 2 * window
